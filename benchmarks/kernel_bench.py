@@ -1,8 +1,8 @@
 """Kernel micro-bench: PIMnast-placed Pallas GEMV vs the jnp oracle, plus
 dispatcher-picked vs fixed-kernel latency across the config registry.
 
-On this CPU container the Pallas kernels execute in interpret mode, so
-wall-clock numbers characterize the HARNESS, not TPU performance — the
+Off a TPU the Pallas kernels execute in interpret mode, so wall-clock
+numbers there characterize the HARNESS, not TPU performance — the
 ``derived`` column is therefore the max abs error vs the oracle (the
 correctness contract), and per-kernel modeled HBM-bound time on v5e
 (weight bytes over the TPU backend's modeled bandwidth) is reported as
@@ -71,6 +71,13 @@ SHAPES = [
 DISPATCH_ARCHS = ("gemma3-1b", "olmo-1b", "minitron-8b")
 
 
+def _interprets(backend_name: str) -> bool:
+    """Whether this host runs ``backend_name``'s Pallas kernels through the
+    interpreter: always off the backend's own platform, never on it."""
+    return (backend_name != "cpu"
+            and dispatch.resolve_backend().name != backend_name)
+
+
 def fixed_kernels(backend_name: str) -> tuple[str, ...]:
     """Fixed-kernel comparison rows: the backend's registered non-quant
     set (quant kernels need quantized weights; these rows are bf16/f32)."""
@@ -83,23 +90,27 @@ def fixed_kernels(backend_name: str) -> tuple[str, ...]:
 def kernel_rows() -> list[tuple[str, float, float]]:
     rows = []
     rng = np.random.default_rng(0)
-    hbm_bw = get_backend("tpu").cost_model.bandwidth_bps
+    tpu = get_backend("tpu")
+    hbm_bw = tpu.cost_model.bandwidth_bps
+    # the TPU kernels: native on a TPU, the Pallas interpreter elsewhere
+    interpret = tpu.default_interpret()
+    mode = "interp" if interpret else "native"
     for name, M, K, B in SHAPES:
         w = rng.standard_normal((M, K)).astype(np.float32)
         x = rng.standard_normal((B, K)).astype(np.float32)
         packed = ops.pack_weight(jnp.asarray(w))
         t0 = time.perf_counter()
-        out = ops.placed_gemv(jnp.asarray(x), packed, interpret=True)
+        out = ops.placed_gemv(jnp.asarray(x), packed, interpret=interpret)
         out.block_until_ready()
         dt = (time.perf_counter() - t0) * 1e6
         err = float(np.abs(np.asarray(out) - x @ w.T).max())
-        rows.append((f"kernel/{name}/interp", dt, err))
+        rows.append((f"kernel/{name}/{mode}", dt, err))
         v5e_us = (M * K * 2) / hbm_bw * 1e6
         rows.append((f"kernel/{name}/v5e_hbm_model", v5e_us, 0.0))
         # quantized variant (int8 + block scales)
         pq = ops.quantize_weight(w, bits=8, block=32)
         t0 = time.perf_counter()
-        oq = ops.placed_gemv(jnp.asarray(x), pq, interpret=True)
+        oq = ops.placed_gemv(jnp.asarray(x), pq, interpret=interpret)
         oq.block_until_ready()
         dtq = (time.perf_counter() - t0) * 1e6
         rel = float(
@@ -135,8 +146,8 @@ def dispatch_rows(measure: bool = True,
     backend = get_backend(backend_name)
     fixed = fixed_kernels(backend_name)
     # The TPU/GPU backends need the explicit interpret opt-in to run their
-    # Pallas kernels on a CPU host; the CPU backend runs natively.
-    interp = backend_name != "cpu"
+    # Pallas kernels on another platform's host; the CPU backend is XLA.
+    interp = _interprets(backend_name)
     rng = np.random.default_rng(0)
     rows = []
     for name, M, K, B in registry_gemv_shapes():
@@ -220,7 +231,7 @@ def program_rows(backend_name: str = "tpu") -> list[dict]:
     acceptance criteria lock), and the modeled latency of both.
     """
     backend = get_backend(backend_name)
-    interp = backend_name != "cpu"
+    interp = _interprets(backend_name)
     policy = DispatchPolicy(backend=backend_name, interpret=interp)
     rows = []
     for name, kind, Ms, K, batch, group in registry_program_shapes():
@@ -265,6 +276,7 @@ def pipeline_rows(backend_name: str = "tpu",
     )
 
     backend = get_backend("tpu")
+    interp = backend.default_interpret()
     rng = np.random.default_rng(0)
     rows = []
     for name, M, K, B in registry_gemv_shapes():
@@ -298,11 +310,12 @@ def pipeline_rows(backend_name: str = "tpu",
                 for depth, plan in ((1, base), (2, deep)):
                     row[f"measured_us/depth{depth}"] = dispatch.time_gemv_us(
                         lambda plan=plan: backend.execute(
-                            kern, xj, pw, plan, interpret=True),
+                            kern, xj, pw, plan, interpret=interp),
                         reps=2,
                     )
                     outs[depth] = np.asarray(
-                        backend.execute(kern, xj, pw, plan, interpret=True))
+                        backend.execute(kern, xj, pw, plan,
+                                        interpret=interp))
                 row["max_abs_diff"] = float(
                     np.abs(outs[1] - outs[2]).max())
             rows.append(row)
@@ -352,7 +365,7 @@ def moe_rows(backend_name: str = "tpu") -> list[dict]:
     from repro.models.layers import _capacity
 
     backend = get_backend(backend_name)
-    interp = backend_name != "cpu"
+    interp = _interprets(backend_name)
     policy = DispatchPolicy(backend=backend_name, interpret=interp)
     B = MOE_DECODE_BATCH
     rows = []

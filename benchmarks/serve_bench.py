@@ -7,9 +7,10 @@ TTFT and per-token decode latency (§V/§VII) — and snapshots the GEMV
 dispatcher's decision counters per run, so the scheduler's batch-shaping
 policy (``gemv_aware`` keeping decode under the dispatcher's batch gate vs
 ``fcfs`` filling every slot) shows up as a measurable change in the
-GEMV-vs-matmul dispatch mix.  Everything runs on ``reduced()`` configs on
-the host — wall-clock numbers characterize the serving harness, not TPU
-performance; the dispatch-mix and scheduling behavior are real.
+GEMV-vs-matmul dispatch mix.  By default it serves ``reduced()`` configs;
+``--full-width`` serves the published config.  Its wall-clock numbers are
+host-clock harness numbers, not a chip benchmark; the dispatch-mix and
+scheduling behavior are real.
 
     PYTHONPATH=src python benchmarks/serve_bench.py                # full trace
     PYTHONPATH=src python benchmarks/serve_bench.py --smoke --json SERVE.json
@@ -26,7 +27,9 @@ performance; the dispatch-mix and scheduling behavior are real.
 from __future__ import annotations
 
 import argparse
+import os
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.bench import SCHEMA_VERSION, run_serve_trace  # noqa: F401
 from repro.serving.scheduler import POLICIES
 
@@ -126,6 +129,10 @@ def main(argv=None) -> int:
                          "per-page scales, kernels.kv_quant)")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny trace + slot count (CI leg)")
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the registry config at its published "
+                         "widths, depth and dtype (default: its reduced() "
+                         "CPU preset)")
     ap.add_argument("--json", metavar="OUT", default=None,
                     help="write the schema-versioned comparison document")
     ap.add_argument("--trace-out", metavar="PATH", default=None,
@@ -139,6 +146,8 @@ def main(argv=None) -> int:
                          "for the predicted-vs-measured drift report; "
                          "default: on when --trace-out is set")
     args = ap.parse_args(argv)
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
 
     policies = POLICIES if args.policy == "all" else (args.policy,)
     tcfg = None
@@ -160,7 +169,7 @@ def main(argv=None) -> int:
         kv_store=args.kv_store,
         trace_config=tcfg,
         trace_out=args.trace_out, trace_timing=args.trace_timing,
-        out=args.json,
+        full_width=args.full_width, out=args.json,
     )
     for run in doc["runs"]:
         print_run(run)

@@ -2,8 +2,8 @@
 
 1. Plan a PIMnast placement for a GEMV and read the modeled speedup
    (the LPDDR-PIM reproduction).
-2. Run the SAME placement idea as a TPU Pallas kernel (interpret mode on
-   CPU) and check it against the jnp oracle.
+2. Run the SAME placement idea as a TPU Pallas kernel (native on a TPU,
+   interpret mode elsewhere) and check it against the jnp oracle.
 3. Peek at the mesh-level placement the planner would use on a pod.
 
     PYTHONPATH=src python examples/quickstart.py
@@ -41,19 +41,22 @@ def main():
     w = rng.standard_normal((M, K), dtype=np.float32)
     x = rng.standard_normal((B, K), dtype=np.float32)
     packed = ops.pack_weight(jnp.asarray(w))   # "column-major" placement
-    # The TPU backend's selection is what placed_gemv(interpret=True)
-    # actually executes (interpret=True resolves the tpu backend).
-    kernel, plan = get_backend("tpu").select_kernel(M, K, B)
+    # The TPU backend's selection is what placed_gemv executes: natively
+    # on a TPU, and off it through the Pallas interpreter (interpret=True
+    # resolves the tpu backend on any host).
+    tpu = get_backend("tpu")
+    interpret = tpu.default_interpret()
+    kernel, plan = tpu.select_kernel(M, K, B)
     desc = (f"m_blk={plan.m_blk} k_blk={plan.k_blk} grid={plan.grid} "
             f"split_k={plan.split_k}" if plan is not None else "XLA ref")
     print(f"TPU kernel plan for {M}x{K}: kernel={kernel} {desc}")
-    out = ops.placed_gemv(jnp.asarray(x), packed, interpret=True)
+    out = ops.placed_gemv(jnp.asarray(x), packed, interpret=interpret)
     err = float(np.abs(np.asarray(out) - x @ w.T).max())
     print(f"  pallas-vs-oracle max err: {err:.2e}\n")
 
     # -- 3. quantized decode GEMV (block scale-factors, paper §VI-D2) ------
     pq = ops.quantize_weight(w, bits=8, block=32)
-    out_q = ops.placed_gemv(jnp.asarray(x), pq, interpret=True)
+    out_q = ops.placed_gemv(jnp.asarray(x), pq, interpret=interpret)
     rel = float(np.abs(np.asarray(out_q) - x @ w.T).max()
                 / np.abs(x @ w.T).max())
     print(f"int8 block-scale GEMV rel err vs float: {rel:.3f}")

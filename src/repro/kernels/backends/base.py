@@ -1216,12 +1216,10 @@ class GemvBackend:
         """Universal ragged executor: out[T, M], row t against the expert
         whose count range contains t.
 
-        ``jax.lax.ragged_dot`` where this jax has it (0.4.31+; it
-        partitions cleanly under GSPMD with expert-sharded stacks);
-        otherwise a searchsorted gather + batched contraction — same math,
-        still zero capacity padding.  f32 accumulation either way; rows at
-        or beyond ``sum(counts)`` come back zero (matching the Pallas
-        ragged kernel's tail contract).
+        ``jax.lax.ragged_dot`` (it partitions cleanly under GSPMD with
+        expert-sharded stacks) with f32 accumulation — zero capacity
+        padding; rows at or beyond ``sum(counts)`` come back zero
+        (matching the Pallas ragged kernel's tail contract).
         """
         x = program.x
         counts = program.counts.astype(jnp.int32)
@@ -1230,15 +1228,8 @@ class GemvBackend:
         wf = w.astype(jnp.float32)
         ends = jnp.cumsum(counts)
         T = x.shape[0]
-        if hasattr(jax.lax, "ragged_dot"):
-            out = jax.lax.ragged_dot(
-                xf, wf, group_sizes=counts,
-                preferred_element_type=jnp.float32)
-        else:  # pragma: no cover - exercised on the old-jax CI leg
-            eids = jnp.searchsorted(ends, jnp.arange(T), side="right")
-            eids = jnp.minimum(eids, w.shape[0] - 1)
-            out = jnp.einsum("tk,tkm->tm", xf, wf[eids],
-                             preferred_element_type=jnp.float32)
+        out = jax.lax.ragged_dot(xf, wf, group_sizes=counts,
+                                 preferred_element_type=jnp.float32)
         valid = (jnp.arange(T) < ends[-1])[:, None]
         return jnp.where(valid, out, 0.0).astype(x.dtype)
 
@@ -1329,9 +1320,15 @@ def available_backends() -> tuple[str, ...]:
 
 
 def backend_for_platform(platform: str) -> GemvBackend:
-    """Backend serving a JAX platform name; unknown platforms get ``cpu``
-    (the portable XLA path runs everywhere)."""
-    return get_backend(_PLATFORM_MAP.get(platform, "cpu"))
+    """Backend serving a JAX platform name.  An unclaimed platform is an
+    error: quietly serving it through another backend's kernels would hide
+    which device the dispatcher is really planning for."""
+    name = _PLATFORM_MAP.get(platform)
+    if name is None:
+        raise ValueError(
+            f"no GEMV backend claims JAX platform {platform!r}; claimed: "
+            f"{sorted(_PLATFORM_MAP)} (set DispatchPolicy.backend)")
+    return get_backend(name)
 
 
 def resolve_backend(policy: DispatchPolicy | None = None) -> GemvBackend:

@@ -105,7 +105,7 @@ class CpuBackend(GemvBackend):
     # concatenated weight (one dispatch, one IV stream); grouped/expert
     # programs run through ``cpu_grouped_gemv`` (batched einsum); ragged
     # programs use the universal XLA ragged executor from the base class
-    # (jax.lax.ragged_dot, gather-einsum on older jax).
+    # (jax.lax.ragged_dot).
     program_modes = ("fused", "grouped", "ragged")
     # Measured on the reference container (single-socket DDR): ~1/16 of the
     # TPU analogue's HBM bandwidth, near-zero dispatch cost, and the core

@@ -690,6 +690,55 @@ def _resolve(backend, key: GemvKey,
     return kernel, plan
 
 
+def _kernel_mesh(backend, kernel: str, policy: DispatchPolicy):
+    """The activation mesh a Pallas kernel must run per shard on, or None.
+
+    Mosaic kernels cannot be partitioned by GSPMD: under a mesh with a
+    'model' axis each TPU kernel runs inside ``shard_map`` on the shard
+    its placement gives every chip.  XLA kernels ("ref", and every
+    non-TPU backend) stay whole and GSPMD partitions them as before.
+    """
+    if policy.model_shards <= 1 or backend.name != "tpu" or kernel == "ref":
+        return None
+    from repro.distributed.axes import current_mesh
+
+    return current_mesh()
+
+
+def _execute_per_shard(backend, kernel: str, x: jnp.ndarray,
+                       pw: PackedWeights, axis: str, policy: DispatchPolicy,
+                       interpret: bool, mesh) -> jnp.ndarray:
+    """Run ``kernel`` on each chip's shard of ``x @ W`` (``axis`` is the
+    ShardedPlan placement: "M" rows, "K" split-K partials summed across
+    'model', else replicated) with a plan made for the SHARD's shape."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    if pw.bits != 16:
+        raise NotImplementedError(
+            "quantized weights have no per-shard TPU kernel path")
+    K, M = pw.shape
+    n = policy.model_shards
+    Ms, Ks = ShardedPlan(axis=axis, n_shards=n).shard_shape(M, K)
+    kernel, plan = backend.select_kernel(
+        Ms, Ks, x.shape[0], x_bytes=jnp.dtype(x.dtype).itemsize,
+        policy=dataclasses.replace(policy, kernel=kernel, model_shards=1))
+    x_spec, w_spec, out_spec = {
+        "M": (P(), P(None, "model"), P(None, "model")),
+        "K": (P(None, "model"), P("model", None), P()),
+    }.get(axis, (P(), P(), P()))
+
+    def local(xs, ws):
+        out = backend.execute(kernel, xs, PackedWeights(w_t=ws), plan,
+                              interpret)
+        if axis == "K":  # split-K partials: the cross-chip reduce
+            out = jax.lax.psum(out.astype(jnp.float32), "model")
+        return out.astype(xs.dtype)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=(x_spec, w_spec),
+                         out_specs=out_spec, check_vma=False)(x, pw.w_t)
+
+
 def _dispatch_request(
     req: GemvRequest,
     policy: DispatchPolicy,
@@ -715,6 +764,11 @@ def _dispatch_request(
         key = GemvKey(M=M, K=K, batch=B, bits=pw.bits, block=pw.block,
                       dtype=str(req.x.dtype), backend=backend.name)
         kernel, plan = _resolve(backend, key, policy)
+        mesh = _kernel_mesh(backend, kernel, policy)
+        if mesh is not None:
+            _, sp = _shard_gemv_key(key, policy, backend)
+            return _execute_per_shard(backend, kernel, req.x, pw, sp.axis,
+                                      policy, interpret, mesh)
     return backend.execute(kernel, req.x, pw, plan, interpret)
 
 
@@ -912,7 +966,15 @@ def dispatch_program(
         policy.interpret if policy.interpret is not None
         else backend.default_interpret()
     )
-    pplan = _resolve_program(backend, program.key(backend.name), policy)
+    key = program.key(backend.name)
+    pplan = _resolve_program(backend, key, policy)
+    mesh = (_kernel_mesh(backend, pplan.kernel, policy)
+            if pplan.mode == "fused" else None)
+    if mesh is not None:
+        _, axis = _shard_program_key(key, policy, backend)
+        return _execute_per_shard(backend, pplan.kernel, program.x,
+                                  program.weights, axis, policy, interpret,
+                                  mesh)
     if pplan.mode == "per_request" and program.kind != "ragged":
         # The decomposition IS N single-request dispatches — same plan
         # cache, autotune table, and selection inputs as dispatch_gemv, so

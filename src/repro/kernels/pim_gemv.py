@@ -27,8 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
-from repro.kernels.tpu_plan import TPUGemvPlan
+from repro.kernels.tpu_plan import TPUGemvPlan, vmem_limit_bytes
 
 
 def _gemv_kernel(x_ref, w_ref, out_ref, acc_ref, *,
@@ -89,8 +88,9 @@ def pim_gemv(
         out_specs=pl.BlockSpec((B, plan.m_blk), lambda mi, ki: (0, mi)),
         out_shape=jax.ShapeDtypeStruct((B, M), x.dtype),
         scratch_shapes=[pltpu.VMEM((B, plan.m_blk), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes(plan),
         ),
         interpret=interpret,
         name="pimnast_gemv",

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
 from repro.kernels.tpu_plan import TPUGemvPlan
 
 
@@ -106,7 +105,7 @@ def quant_gemv(
         out_specs=pl.BlockSpec((B, plan.m_blk), lambda mi, ki: (0, mi)),
         out_shape=jax.ShapeDtypeStruct((B, M), x.dtype),
         scratch_shapes=[pltpu.VMEM((B, plan.m_blk), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -146,7 +145,7 @@ def quant4_gemv(
         out_specs=pl.BlockSpec((B, plan.m_blk), lambda mi, ki: (0, mi)),
         out_shape=jax.ShapeDtypeStruct((B, M), x.dtype),
         scratch_shapes=[pltpu.VMEM((B, plan.m_blk), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -16,8 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
-from repro.kernels.tpu_plan import TPUGemvPlan
+from repro.kernels.tpu_plan import TPUGemvPlan, vmem_limit_bytes
 
 
 def _splitk_kernel(x_ref, w_ref, out_ref, acc_ref, *,
@@ -88,8 +87,9 @@ def splitk_gemv(
         ),
         out_shape=jax.ShapeDtypeStruct((deg, B, M), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, B, plan.m_blk), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes(plan),
         ),
         interpret=interpret,
         name="pimnast_splitk_gemv",

@@ -26,6 +26,11 @@ from dataclasses import dataclass
 LANES = 128
 SUBLANES = 8
 DEFAULT_VMEM_BUDGET = 96 * 1024 * 1024  # leave headroom of the ~128MB VMEM
+# What Mosaic allocates beyond the buffers ``_fits`` counts: batch rows
+# padded to whole sublane tiles and the kernel's own stack.  The planner's
+# count alone was measured too small for the 384x2048 lm_head block at
+# batch 4 on v5e; 1 MiB over it compiled every olmo-1b decode plan.
+VMEM_HEADROOM = 4 * 1024 * 1024
 
 
 @dataclass(frozen=True)
@@ -49,6 +54,14 @@ class TPUGemvPlan:
     @property
     def grid(self) -> tuple[int, int]:
         return (self.n_m, self.n_k // self.pipeline_depth)
+
+
+def vmem_limit_bytes(plan: TPUGemvPlan) -> int:
+    """The scoped-VMEM limit a kernel asks the compiler for: the plan's
+    own working set plus headroom.  Without it the compiler applies the
+    chip's default scoped limit (16 MiB on v5e), which refuses the
+    planner's 2048x2048 bf16 blocks although VMEM holds them."""
+    return plan.vmem_bytes + VMEM_HEADROOM
 
 
 def _fits(

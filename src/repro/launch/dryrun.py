@@ -87,23 +87,6 @@ def parse_collective_bytes(hlo: str) -> dict[str, int]:
     return out
 
 
-def cost_analysis_dict(cost) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across JAX versions.
-
-    Older JAX returns one dict; 0.4.3x returns a list with one dict per
-    executable program (or None). We take the first non-empty entry — the
-    per-device program whose FLOPs/bytes the roofline uses.
-    """
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        for entry in cost:
-            if entry:
-                return dict(entry)
-        return {}
-    return dict(cost)
-
-
 def _build_step(cfg, shape, mesh, gemv_backend=None, gemv_fused=True):
     """Returns (fn, kwargs_specs, in_shardings_tree) for this cell.
 
@@ -212,7 +195,7 @@ def _cell_metrics(cfg, shape, mesh) -> dict:
                     donate_argnums=donate)
             .lower(*args).compile()
         )
-    cost = cost_analysis_dict(compiled.cost_analysis())
+    cost = dict(compiled.cost_analysis())
     coll = parse_collective_bytes(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),
@@ -317,7 +300,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     t_compile = time.perf_counter() - t0
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled.cost_analysis())
+    cost = dict(compiled.cost_analysis())
     hlo = compiled.as_text()
     coll = parse_collective_bytes(hlo)
 

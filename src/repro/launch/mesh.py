@@ -17,10 +17,20 @@ import jax
 import numpy as np
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``.  The model places
+    activations with ``with_sharding_constraint`` anchors and leaves the
+    rest to GSPMD propagation (``distributed/axes.py``); JAX's default of
+    ``Explicit`` axes would instead demand an output sharding on every
+    ambiguous op (the embedding gather first) and reject those anchors."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
@@ -30,11 +40,11 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
         raise ValueError(
             f"mesh {shape} needs {n} devices, have {len(jax.devices())}"
         )
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def single_device_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def parse_mesh_arg(arg: str) -> tuple[int, int]:

@@ -148,6 +148,13 @@ def init_lm(key, cfg: ModelConfig) -> Params:
     return p
 
 
+def init_lm_on_device(cfg: ModelConfig, seed: int = 0) -> Params:
+    """Random weights made by ONE jitted program on the default device, in
+    ``cfg.param_dtype``: a published-width model never exists as a float32
+    copy (the normal draws fuse into the cast)."""
+    return jax.jit(init_lm, static_argnums=1)(jax.random.PRNGKey(seed), cfg)
+
+
 # --------------------------------------------------------------------------
 # Cache
 # --------------------------------------------------------------------------

@@ -19,9 +19,10 @@ so its jitted steps re-trace and re-plan; re-planning small shapes is
 microseconds).
 
 CLI wrapper: ``benchmarks/serve_bench.py``; the dry-run exposes the same
-trace as ``python -m repro.launch.dryrun --serve-trace``.  Everything runs
-on ``reduced()`` configs — this is the laptop-scale serving harness, not a
-hardware benchmark.
+trace as ``python -m repro.launch.dryrun --serve-trace``.  By default it
+serves ``reduced()`` configs (the laptop-scale harness); ``full_width``
+serves the published config.  Its times are host-clock numbers, not a
+benchmark.
 """
 
 from __future__ import annotations
@@ -29,7 +30,6 @@ from __future__ import annotations
 import json
 from dataclasses import dataclass
 
-import jax
 import numpy as np
 
 # --json document version: bump when the record layout changes.
@@ -233,6 +233,7 @@ def run_serve_trace(
     trace_config: TraceConfig | None = None,
     trace_out: str | None = None,
     trace_timing: bool | None = None,
+    full_width: bool = False,
     out: str | None = None,
 ) -> dict:
     """Serve one synthetic trace under each policy; returns (and optionally
@@ -266,12 +267,18 @@ def run_serve_trace(
     dispatch decision; it defaults to ON when ``trace_out`` is set so the
     drift report prices kernels with both predicted and measured µs out
     of the box — pass ``False`` to record predicted-only.
+
+    ``full_width=True`` serves the registry config as published (its
+    widths, depth and dtype) instead of its ``reduced()`` CPU preset; the
+    document's ``reduced`` field says which ran.
     """
     from repro.configs.registry import get_config
     from repro.models import lm
 
-    cfg = get_config(arch).reduced()
-    params = lm.init_lm(jax.random.PRNGKey(0), cfg)
+    cfg = get_config(arch)
+    if not full_width:
+        cfg = cfg.reduced()
+    params = lm.init_lm_on_device(cfg)
     mesh = None
     if mesh_shape is not None:
         from repro.launch.mesh import make_mesh
@@ -307,7 +314,7 @@ def run_serve_trace(
     doc = {
         "schema": SCHEMA_VERSION,
         "arch": arch,
-        "reduced": True,
+        "reduced": not full_width,
         "mesh": (None if mesh is None
                  else {k: int(v) for k, v in mesh.shape.items()}),
         "trace": {

@@ -79,8 +79,9 @@ def test_platform_mapping_covers_gpu_spellings():
     for platform in ("gpu", "cuda", "rocm"):
         assert backend_for_platform(platform).name == "gpu"
     assert backend_for_platform("tpu").name == "tpu"
-    # unknown platforms get the portable XLA path, not an error
-    assert backend_for_platform("weird-accelerator").name == "cpu"
+    # an unclaimed platform is an error, never a silent cpu fallback
+    with pytest.raises(ValueError, match="no GEMV backend claims"):
+        backend_for_platform("weird-accelerator")
 
 
 def test_register_backend_rejects_anonymous_and_allows_custom():

@@ -246,9 +246,13 @@ def test_radix_evict_to_respects_pins():
 # --------------------------------------------------------------------------
 # Hypothesis properties
 # --------------------------------------------------------------------------
+# No per-example deadline: each example draws new array lengths, and the
+# first eager JAX op at a new shape compiles, so an example's wall time is
+# mostly compilation that depends on what ran before it in the process
+# (Hypothesis then reports the property as Flaky, not as falsified).
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10_000))
 def test_property_insert_match_roundtrip(seed):
     """Any mix of overlapping streams from a tiny alphabet: every inserted
@@ -270,7 +274,7 @@ def test_property_insert_match_roundtrip(seed):
     assert pc.bytes_used == live
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10_000))
 def test_property_refcounts_and_eviction(seed):
     """Random acquire/release/evict interleavings: refcounts never go
@@ -304,7 +308,7 @@ def test_property_refcounts_and_eviction(seed):
     assert all(n.refcount == 0 for n in pc._walk())
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10_000))
 def test_property_compact_preserves_segment_contents(seed):
     """SlotKVCache defrag never corrupts what extract_prefix reads: after

@@ -82,6 +82,46 @@ def test_shard_program_key_prefers_experts_then_rows():
     assert axis == "K" and key.K == 24 and key.Ms == (30, 30)
 
 
+@pytest.mark.parametrize("axis", ["M", "K", "replicated"])
+def test_tpu_kernels_run_per_shard_on_mesh(axis):
+    """Mosaic kernels cannot be partitioned by GSPMD, so on a mesh each
+    TPU kernel runs inside shard_map on its placement's shard: output rows
+    (M), split-K partials summed over 'model' (K), or the whole GEMV
+    (replicated).  Interpret mode here; every result must equal x @ W."""
+    res = run_sub(f"""
+import json
+import numpy as np
+import jax
+from repro.distributed.axes import activation_mesh
+from repro.kernels import dispatch
+from repro.kernels.backends import get_backend
+from repro.kernels.dispatch import DispatchPolicy, _execute_per_shard
+from repro.kernels.ops import PackedWeights
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((1, 4), ("data", "model"))
+rng = np.random.default_rng(0)
+x = rng.standard_normal((2, 1024)).astype(np.float32)
+w = rng.standard_normal((1024, 512)).astype(np.float32)
+pol = DispatchPolicy(interpret=True, model_shards=4)
+tpu = get_backend("tpu")
+err = {{}}
+for kernel in ("pim", "splitk"):
+    f = jax.jit(lambda a, b: _execute_per_shard(
+        tpu, kernel, a, PackedWeights(w_t=b), "{axis}", pol, True, mesh))
+    err[kernel] = float(np.abs(np.asarray(f(x, w)) - x @ w).max())
+# the dispatcher takes the per-shard path on its own under a mesh
+with activation_mesh(mesh):
+    out = jax.jit(lambda a, b: dispatch.dispatch_gemv(
+        a, dispatch.from_transposed(b),
+        policy=DispatchPolicy(interpret=True, model_shards=4,
+                              kernel="pim")))(x, w)
+err["dispatch"] = float(np.abs(np.asarray(out) - x @ w).max())
+print(json.dumps(err))
+""", devices=4)
+    assert max(res.values()) < 1e-3, res
+
+
 # --------------------------------------------------------------------------
 # Serving cache placement rules (pure functions of shapes + mesh)
 # --------------------------------------------------------------------------

@@ -184,7 +184,8 @@ def dispatch_stats() -> dict:
     reflect the *dispatch mix* the traced programs bake in — e.g. a serving
     scheduler that caps decode batches at the GEMV threshold shifts
     decisions from ``matmul_fallback`` to ``gemv_path`` (serving/metrics
-    snapshots this per engine step).  Reset by :func:`clear_plan_cache`.
+    reports it per engine run, as a delta against the engine's start).
+    Reset by :func:`clear_plan_cache`.
     """
     # Deep-copy the whole counter tree in ONE lock hold: every section of
     # the returned snapshot is from the same instant, and no returned

@@ -11,9 +11,50 @@ One :class:`Tracer` per observed engine run.  Three design rules:
    construction, so a trace is self-consistent even across engines with
    different wall-clock bases.
 3. **Zero-cost when absent.**  Hot paths guard on
-   ``current_tracer() is None`` — a module-slot read and an ``is`` check.
-   The dispatch hook additionally only runs on plan-cache *misses*, so the
-   cached decode hot path never sees it at all.
+   ``current_tracer() is None`` (or the engine's ``self.tracer is None``)
+   — a slot read and an ``is`` check; with no tracer no span, profiler
+   annotation or attribute dict is built.  The dispatch hook additionally
+   only runs on plan-cache *misses*, so the cached decode hot path never
+   sees it at all.
+
+Span tree and the profiler's clock
+----------------------------------
+:meth:`Tracer.span` (a ``with`` block) is also a profiler annotation:
+it enters ``jax.profiler.TraceAnnotation("<cat>.<name>", **attrs)``
+around its body (scalar attrs only; attrs the body adds are attached on
+exit), so a profiled run shows the engine's spans — ``engine.step``,
+``engine.decode_step``, ``engine.kv.merge``, ... — on the host thread
+that ran them, stamped on the profiler's own clock, with the device
+programs they launched beside them.  The annotation costs next to
+nothing while no profiler session is active.  The ring-buffer record is
+unchanged: the span still lands in ``tracer.spans`` on the tracer's
+clock.
+
+Each span records its enclosing span's name (``Span.parent``) from a
+per-thread stack of open ``with`` blocks; :meth:`Tracer.add_span`
+(retroactive spans: ``prefill_chunk``, ``async_prefill``,
+``prefix_match``) records the innermost open block as its parent but
+emits no annotation.  One engine step nests so::
+
+    step                       (decode_rows, admitted)
+    ├ step.admit               expire, preempt, select, phase bookkeeping
+    ├ prefill_wave             (requests, tokens)
+    │ ├ prefill.launch         the jitted prefill call returning
+    │ ├ kv.splice              splice + prefix inserts (rows)
+    │ ├ prefill.fetch          the first-token logits to the host
+    │ └ prefill.sample         sample + activate (rows)
+    ├ kv.compact               each defrag pass (moves)
+    └ decode_step              (bucket, active, defrag_moves, finished,
+      │                         rows, live_positions)
+      ├ kv.slice               bucket slice, snapshots, inputs (bucket)
+      ├ decode.launch          the jitted decode call returning
+      ├ kv.merge               merge + snapshot restores
+      ├ decode.fetch           logits to the host: waits on the device
+      └ decode.sample          sample, last-token writes, finish (rows,
+                               finished)
+
+Request-phase spans cross steps, so they have no parent and stay on the
+tracer's clock alone.
 
 Request phase machine
 ---------------------
@@ -47,6 +88,8 @@ from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
+import jax
+
 # Summary-document / trace-buffer layout version (export.py stamps it).
 SCHEMA_VERSION = 1
 
@@ -73,6 +116,7 @@ class Span:
     dur_us: float
     rid: int | None = None
     attrs: dict = field(default_factory=dict)
+    parent: str | None = None   # name of the enclosing span, same thread
 
 
 @dataclass(frozen=True)
@@ -117,6 +161,12 @@ class DispatchRecord:
         return robust_us(self.trials_us)
 
 
+def _scalars(attrs: dict) -> dict:
+    """The attrs a profiler annotation can carry."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (bool, int, float, str))}
+
+
 def _percentile(sorted_vals: list[float], p: float) -> float:
     """Linear-interpolated percentile of an already-sorted list."""
     if not sorted_vals:
@@ -157,6 +207,7 @@ class Tracer:
                         "dispatches": 0, "requests": 0}
         self._open: dict[int, dict] = {}   # rid -> in-flight request state
         self._lock = threading.Lock()
+        self._local = threading.local()    # .stack: open span names
 
     # -- clock ---------------------------------------------------------------
 
@@ -185,28 +236,56 @@ class Tracer:
             self._append(self.counters, "counters",
                          CounterSample(name, t, float(value)))
 
+    def _stack(self) -> list:
+        """This thread's open ``span`` blocks, innermost last."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     def add_span(self, name: str, start_us: float, end_us: float, *,
                  cat: str = "engine", track: str = "engine",
                  rid: int | None = None, **attrs) -> None:
+        stack = self._stack()
+        parent = stack[-1] if stack else None
         with self._lock:
             self._append(self.spans, "spans",
                          Span(name, cat, track, start_us,
-                              end_us - start_us, rid, attrs))
+                              end_us - start_us, rid, attrs, parent))
 
     @contextmanager
     def span(self, name: str, *, cat: str = "engine",
              track: str = "engine", rid: int | None = None, **attrs):
         """Measure a with-block as one span.  Yields the (mutable) attrs
-        dict so the body can attach results (e.g. defrag move counts)."""
-        t0 = self.now_us()
+        dict so the body can attach results (e.g. defrag move counts).
+
+        The block is also the profiler annotation ``<cat>.<name>``
+        carrying the scalar attrs (and ``rid``); those the body adds or
+        changes are attached when it exits."""
         a = dict(attrs)
+        meta = _scalars(a)
+        if rid is not None:
+            meta["rid"] = rid
+        ann = jax.profiler.TraceAnnotation(f"{cat}.{name}", **meta)
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        stack.append(name)
+        ann.__enter__()
+        t0 = self.now_us()
         try:
             yield a
         finally:
             t1 = self.now_us()
+            late = {k: v for k, v in _scalars(a).items()
+                    if k not in meta or meta[k] != v}
+            if late:
+                ann.set_metadata(**late)
+            ann.__exit__(None, None, None)
+            stack.pop()
             with self._lock:
                 self._append(self.spans, "spans",
-                             Span(name, cat, track, t0, t1 - t0, rid, a))
+                             Span(name, cat, track, t0, t1 - t0, rid, a,
+                                  parent))
 
     # -- request phase machine ----------------------------------------------
 

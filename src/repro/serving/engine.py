@@ -14,8 +14,8 @@
   request level), waiting-queue backpressure, per-request deadlines, and
   (``preempt_margin``) slot eviction for deadline-imminent queued work;
 * :class:`~repro.serving.metrics.ServingMetrics` — TTFT / per-token-latency
-  / throughput histograms plus per-step GEMV-dispatcher counter snapshots,
-  exportable as a schema-versioned JSON document;
+  / throughput histograms, per-step records and the whole run's
+  GEMV-dispatcher counters, exportable as a schema-versioned JSON document;
 * :mod:`~repro.serving.sampling` — temperature/top-k/top-p sampling,
   greedy-compatible (the default stays exact argmax);
 * :class:`~repro.serving.prefix_cache.PrefixCache` (opt-in,
@@ -44,7 +44,7 @@ concat), MoE expert FFNs as grouped programs, the LM head as a single
 request.  The engine decodes a defragmented power-of-two *bucket* of active
 slots, so the scheduler's admission cap is what decides whether those
 dispatches stay GEMV-shaped or fall back to the XLA matmul path — the mix
-is visible in ``dispatch_stats()`` and in every metrics snapshot.
+is visible in ``dispatch_stats()`` and in the metrics document.
 
 Sharded mode (DESIGN.md §9): constructed with a ``mesh``, the engine runs
 the same serving loop over a device mesh end-to-end — decode params placed
@@ -151,6 +151,12 @@ def build_serve_fns(cfg: ModelConfig, max_len: int,
 
 def greedy(logits: jnp.ndarray) -> jnp.ndarray:
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+# Entered in place of a ``Tracer.span`` when no tracer is installed: the
+# hot path builds neither a span nor its attrs (``with (tr.span(...) if tr
+# is not None else _NO_SPAN)``).
+_NO_SPAN = contextlib.nullcontext()
 
 
 def _next_pow2(n: int) -> int:
@@ -518,9 +524,83 @@ class Engine:
         """One engine iteration: expire + (maybe preempt) + admit + chunked
         prefill advance + one decode step.  Returns requests completed this
         step."""
-        t0 = self.clock()
         tr = self.tracer
-        expired = self.scheduler.expire(t0)
+        with (tr.span("step") if tr is not None else _NO_SPAN) as sp:
+            t0 = self.clock()
+            with (tr.span("step.admit") if tr is not None
+                  else _NO_SPAN) as sa:
+                admitted = self._admit(t0)
+                if tr is not None:
+                    sa.update(admitted=len(admitted),
+                              queue=len(self.scheduler))
+            finished: list[Request] = []
+            if admitted:
+                misses = admitted
+                if self.prefix is not None:
+                    # prefix hits splice their cached segments and join the
+                    # chunked-prefill continuation with only the private
+                    # tail left to run; misses take the normal prefill
+                    # paths below
+                    misses = []
+                    for r in admitted:
+                        m = self._prefix_match(r)
+                        if m is not None:
+                            self._admit_prefix_hit(r, m)
+                        else:
+                            misses.append(r)
+                if self.async_prefill:
+                    # EVERY miss prefills through the async chunk chain —
+                    # even single-chunk prompts get their splice+sample
+                    # hidden behind the intervening decode step
+                    chunked = list(misses)
+                elif self.prefill_chunk:
+                    chunked = [r for r in misses
+                               if len(self._pending_tokens(r))
+                               > self.prefill_chunk]
+                else:
+                    chunked = []
+                chunked_ids = {id(r) for r in chunked}
+                plain = [r for r in misses if id(r) not in chunked_ids]
+                if plain:
+                    finished.extend(self._prefill(plain))
+                for r in chunked:
+                    # alloc now (the admission decision spent this slot);
+                    # the first chunk splices in the advance pass below
+                    slot = self.kv.alloc()
+                    self._prefilling[slot] = [r, 0]
+                    if tr is not None:
+                        tr.request_annotate(r.rid, slot=slot)
+            if self._prefilling:
+                finished.extend(self._advance_chunked())
+            # an instant finish (eos / max_new_tokens=1 at prefill) can
+            # punch a hole in the active prefix; decode needs it contiguous
+            self._defrag_moves = 0
+            self._compact()
+            decode_batch, decode_s = 0, 0.0
+            if self.active:
+                done, decode_batch, decode_s = self._decode()
+                finished.extend(done)
+            self._compact()
+            t1 = self.clock()
+            self.metrics.record_step(
+                t1, step_s=t1 - t0, decode_s=decode_s,
+                decode_batch=decode_batch, n_active=self.kv.n_active,
+                queue_depth=len(self.scheduler),
+            )
+            if tr is not None:
+                sp.update(decode_rows=decode_batch, admitted=len(admitted))
+                # per-step gauges -> counter tracks in the exported trace
+                tr.counter("queue_depth", len(self.scheduler))
+                tr.counter("active_slots", self.kv.n_active)
+                tr.counter("decode_batch", decode_batch)
+        return finished
+
+    def _admit(self, now: float) -> list[Request]:
+        """Expire overdue queued requests, preempt for a deadline if the
+        scheduler asks, and take this step's admissions from the
+        scheduler (stamped with their admission order).  Returns them."""
+        tr = self.tracer
+        expired = self.scheduler.expire(now)
         for r in expired:
             r.expired = True
             if tr is not None:
@@ -529,7 +609,7 @@ class Engine:
         if expired:
             self.metrics.requests_expired(len(expired))
 
-        self._maybe_preempt(t0)
+        self._maybe_preempt(now)
         if self.scheduler.config.moe_experts > 1:
             # Expert-aware batch shaping: refresh the scheduler's router-
             # skew estimate from this engine's dispatch deltas before it
@@ -537,74 +617,15 @@ class Engine:
             self.scheduler.observe_expert_load(
                 self.metrics.dispatch_delta().get("expert_load", {}))
         admitted = self.scheduler.select(self.kv.n_free, self.kv.n_active,
-                                         t0)
-        finished: list[Request] = []
-        if admitted:
-            for r in admitted:
-                r.admit_seq = self._admit_seq
-                self._admit_seq += 1
-                if tr is not None:
-                    # queued -> prefill (readmitted victims transition
-                    # preempted -> prefill through the same call)
-                    tr.request_phase(r.rid, "prefill",
-                                     admit_seq=r.admit_seq)
-            misses = admitted
-            if self.prefix is not None:
-                # prefix hits splice their cached segments and join the
-                # chunked-prefill continuation with only the private tail
-                # left to run; misses take the normal prefill paths below
-                misses = []
-                for r in admitted:
-                    m = self._prefix_match(r)
-                    if m is not None:
-                        self._admit_prefix_hit(r, m)
-                    else:
-                        misses.append(r)
-            if self.async_prefill:
-                # EVERY miss prefills through the async chunk chain — even
-                # single-chunk prompts get their splice+sample hidden
-                # behind the intervening decode step
-                chunked = list(misses)
-            elif self.prefill_chunk:
-                chunked = [r for r in misses
-                           if len(self._pending_tokens(r))
-                           > self.prefill_chunk]
-            else:
-                chunked = []
-            chunked_ids = {id(r) for r in chunked}
-            plain = [r for r in misses if id(r) not in chunked_ids]
-            if plain:
-                finished.extend(self._prefill(plain))
-            for r in chunked:
-                # alloc now (the admission decision spent this slot); the
-                # first chunk splices in the advance pass below
-                slot = self.kv.alloc()
-                self._prefilling[slot] = [r, 0]
-                if tr is not None:
-                    tr.request_annotate(r.rid, slot=slot)
-        if self._prefilling:
-            finished.extend(self._advance_chunked())
-        # an instant finish (eos / max_new_tokens=1 at prefill) can punch a
-        # hole in the active prefix; decode needs it contiguous
-        self._defrag_moves = 0
-        self._compact()
-        decode_batch, decode_s = 0, 0.0
-        if self.active:
-            done, decode_batch, decode_s = self._decode()
-            finished.extend(done)
-        self._compact()
-        t1 = self.clock()
-        self.metrics.record_step(
-            t1, step_s=t1 - t0, decode_s=decode_s,
-            decode_batch=decode_batch, n_active=self.kv.n_active,
-            queue_depth=len(self.scheduler),
-        )
-        if tr is not None:
-            # per-step gauges -> counter tracks in the exported trace
-            tr.counter("queue_depth", len(self.scheduler))
-            tr.counter("active_slots", self.kv.n_active)
-            tr.counter("decode_batch", decode_batch)
-        return finished
+                                         now)
+        for r in admitted:
+            r.admit_seq = self._admit_seq
+            self._admit_seq += 1
+            if tr is not None:
+                # queued -> prefill (readmitted victims transition
+                # preempted -> prefill through the same call)
+                tr.request_phase(r.rid, "prefill", admit_seq=r.admit_seq)
+        return admitted
 
     def run_until_drained(self, max_iters: int = 1000) -> list[Request]:
         done: list[Request] = []
@@ -686,48 +707,54 @@ class Engine:
 
     def _prefill_wave(self, wave: list[Request]) -> list[Request]:
         tr = self.tracer
-        wave_t0 = tr.now_us() if tr is not None else 0.0
-        slots = [self.kv.alloc() for _ in wave]
-        if tr is not None:
-            for r, slot in zip(wave, slots):
-                tr.request_annotate(r.rid, slot=slot)
-        toks = [self._pending_tokens(r) for r in wave]
-        lengths = [len(t) for t in toks]
-        Lmax = max(lengths)
-        if self.cfg.family == "ssm" or self.cfg.parallel_ssm:
-            Lpad = Lmax  # exact: no pads through the recurrence
-        else:
-            Lpad = max(min(_next_pow2(Lmax), self.max_len), Lmax)
-        nb = min(_next_pow2(len(wave)), self.slots)
-        tokens = np.zeros((nb, Lpad), np.int32)
-        lens = np.ones((nb,), np.int32)
-        for i, t in enumerate(toks):
-            tokens[i, :lengths[i]] = t
-            lens[i] = lengths[i]
-        # batch-pad rows reuse the first slot's modality features
-        row_idx = slots + [slots[0]] * (nb - len(wave))
-        extra = {k: v[jnp.asarray(row_idx)] for k, v in self._extra.items()}
-        sub = lm.init_cache(self.cfg, nb, self.max_len, per_slot_pos=True,
-                            kv_store=self.kv_store)
-        with self._mesh_ctx():
-            last, sub = self._jit_prefill(
-                self.params, jnp.asarray(tokens), jnp.asarray(lens), sub,
-                extra,
-            )
-        self.kv.splice(sub, slots, lengths)
-        for slot, t in zip(slots, toks):
-            self._prefix_insert(slot, t)
-        last_np = np.asarray(last)
-        now = self.clock()
-        finished = []
-        for i, (r, slot) in enumerate(zip(wave, slots)):
-            tok = self._sample(r, last_np[i])
-            if self._activate(r, slot, tok, now):
-                finished.append(r)
-        self.metrics.prefill_wave(len(wave), sum(lengths))
-        if tr is not None:
-            tr.add_span("prefill_wave", wave_t0, tr.now_us(),
-                        requests=len(wave), tokens=sum(lengths))
+        with (tr.span("prefill_wave") if tr is not None else _NO_SPAN) as sw:
+            slots = [self.kv.alloc() for _ in wave]
+            if tr is not None:
+                for r, slot in zip(wave, slots):
+                    tr.request_annotate(r.rid, slot=slot)
+            toks = [self._pending_tokens(r) for r in wave]
+            lengths = [len(t) for t in toks]
+            Lmax = max(lengths)
+            if self.cfg.family == "ssm" or self.cfg.parallel_ssm:
+                Lpad = Lmax  # exact: no pads through the recurrence
+            else:
+                Lpad = max(min(_next_pow2(Lmax), self.max_len), Lmax)
+            nb = min(_next_pow2(len(wave)), self.slots)
+            tokens = np.zeros((nb, Lpad), np.int32)
+            lens = np.ones((nb,), np.int32)
+            for i, t in enumerate(toks):
+                tokens[i, :lengths[i]] = t
+                lens[i] = lengths[i]
+            # batch-pad rows reuse the first slot's modality features
+            row_idx = slots + [slots[0]] * (nb - len(wave))
+            extra = {k: v[jnp.asarray(row_idx)]
+                     for k, v in self._extra.items()}
+            sub = lm.init_cache(self.cfg, nb, self.max_len,
+                                per_slot_pos=True, kv_store=self.kv_store)
+            with (tr.span("prefill.launch") if tr is not None
+                  else _NO_SPAN), self._mesh_ctx():
+                last, sub = self._jit_prefill(
+                    self.params, jnp.asarray(tokens), jnp.asarray(lens), sub,
+                    extra,
+                )
+            with (tr.span("kv.splice", rows=len(slots)) if tr is not None
+                  else _NO_SPAN):
+                self.kv.splice(sub, slots, lengths)
+                for slot, t in zip(slots, toks):
+                    self._prefix_insert(slot, t)
+            with (tr.span("prefill.fetch") if tr is not None else _NO_SPAN):
+                last_np = np.asarray(last)
+            with (tr.span("prefill.sample", rows=len(wave)) if tr is not None
+                  else _NO_SPAN):
+                now = self.clock()
+                finished = []
+                for i, (r, slot) in enumerate(zip(wave, slots)):
+                    tok = self._sample(r, last_np[i])
+                    if self._activate(r, slot, tok, now):
+                        finished.append(r)
+            self.metrics.prefill_wave(len(wave), sum(lengths))
+            if tr is not None:
+                sw.update(requests=len(wave), tokens=sum(lengths))
         return finished
 
     def _advance_chunked(self) -> list[Request]:
@@ -900,53 +927,72 @@ class Engine:
     def _decode(self) -> tuple[list[Request], int, float]:
         t0 = self.clock()
         tr = self.tracer
-        step_t0 = tr.now_us() if tr is not None else 0.0
-        n = self.kv.n_active  # compact() keeps alloc'd slots a prefix
-        b = min(_next_pow2(n), self.slots)
-        if self.gemv_policy is not None:
-            # Don't let power-of-two rounding push the batch past the
-            # dispatcher's GEMV gate when the actives themselves fit under
-            # it (a non-pow2 threshold would otherwise silently defeat the
-            # gemv_aware policy); the threshold-sized bucket is one extra
-            # jit shape, still bounded.
-            thresh = self.gemv_policy.batch_threshold
-            if n <= thresh < b:
-                b = thresh
-        # Chunked-prefill rows sit inside the alloc'd prefix the bucket
-        # covers; decode must not advance their mid-prompt state, so their
-        # rows are snapshotted and restored after the merge (their logits
-        # are never sampled — only ``self.active`` rows are).  Async mode
-        # drops the snapshot/restore entirely: the slot holds no real KV
-        # until the harvest splice fully defines it, so whatever decode
-        # writes there is junk-on-junk (per-slot rows are independent).
-        snaps = ({} if self.async_prefill else
-                 {s: (self.kv.slot_view(s), self._prefilling[s][1])
-                  for s in self._prefilling if s < b})
-        cache_b = self.kv.slice_prefix(b)
-        extra_b = {k: v[:b] for k, v in self._extra.items()}
-        with self._mesh_ctx():
-            logits, new_cache = self._jit_decode(
-                self.params, self.last_tok[:b], cache_b, extra_b
-            )
-        self.kv.merge_prefix(new_cache, b)
-        for s, (snap, consumed) in snaps.items():
-            self.kv.splice(snap, [s], [consumed])
-        logits_np = np.asarray(logits)
-        decode_s = self.clock() - t0
-        now = self.clock()
-        finished = []
-        for slot, r in list(self.active.items()):
-            tok = self._sample(r, logits_np[slot])
-            r.generated.append(tok)
-            self.last_tok = self.last_tok.at[slot, 0].set(tok)
-            self.metrics.tokens_generated(1)
-            if self._should_finish(r, tok):
-                self._finish(r, slot, now)
-                finished.append(r)
-        if tr is not None:
-            tr.add_span("decode_step", step_t0, tr.now_us(), bucket=b,
-                        active=n, defrag_moves=self._defrag_moves,
-                        finished=len(finished))
+        with (tr.span("decode_step") if tr is not None else _NO_SPAN) as sd:
+            n = self.kv.n_active  # compact() keeps alloc'd slots a prefix
+            b = min(_next_pow2(n), self.slots)
+            if self.gemv_policy is not None:
+                # Don't let power-of-two rounding push the batch past the
+                # dispatcher's GEMV gate when the actives themselves fit
+                # under it (a non-pow2 threshold would otherwise silently
+                # defeat the gemv_aware policy); the threshold-sized bucket
+                # is one extra jit shape, still bounded.
+                thresh = self.gemv_policy.batch_threshold
+                if n <= thresh < b:
+                    b = thresh
+            with (tr.span("kv.slice", bucket=b) if tr is not None
+                  else _NO_SPAN):
+                # Chunked-prefill rows sit inside the alloc'd prefix the
+                # bucket covers; decode must not advance their mid-prompt
+                # state, so their rows are snapshotted and restored after
+                # the merge (their logits are never sampled — only
+                # ``self.active`` rows are).  Async mode drops the
+                # snapshot/restore entirely: the slot holds no real KV until
+                # the harvest splice fully defines it, so whatever decode
+                # writes there is junk-on-junk (per-slot rows are
+                # independent).
+                snaps = ({} if self.async_prefill else
+                         {s: (self.kv.slot_view(s), self._prefilling[s][1])
+                          for s in self._prefilling if s < b})
+                cache_b = self.kv.slice_prefix(b)
+                last_b = self.last_tok[:b]
+                extra_b = {k: v[:b] for k, v in self._extra.items()}
+            with (tr.span("decode.launch") if tr is not None
+                  else _NO_SPAN), self._mesh_ctx():
+                logits, new_cache = self._jit_decode(
+                    self.params, last_b, cache_b, extra_b
+                )
+            with (tr.span("kv.merge") if tr is not None else _NO_SPAN):
+                self.kv.merge_prefix(new_cache, b)
+                for s, (snap, consumed) in snaps.items():
+                    self.kv.splice(snap, [s], [consumed])
+            with (tr.span("decode.fetch") if tr is not None else _NO_SPAN):
+                logits_np = np.asarray(logits)
+            decode_s = self.clock() - t0
+            now = self.clock()
+            finished = []
+            rows = list(self.active.items())
+            with (tr.span("decode.sample", rows=len(rows)) if tr is not None
+                  else _NO_SPAN) as ss:
+                for slot, r in rows:
+                    tok = self._sample(r, logits_np[slot])
+                    r.generated.append(tok)
+                    self.last_tok = self.last_tok.at[slot, 0].set(tok)
+                    self.metrics.tokens_generated(1)
+                    if self._should_finish(r, tok):
+                        self._finish(r, slot, now)
+                        finished.append(r)
+                if tr is not None:
+                    ss["finished"] = len(finished)
+            if tr is not None:
+                # live_positions: the positions the decoded rows' caches
+                # hold after this step (their kv_valid_len), counted on the
+                # host so the span forces no device sync
+                sd.update(bucket=b, active=n,
+                          defrag_moves=self._defrag_moves,
+                          finished=len(finished), rows=len(rows),
+                          live_positions=sum(
+                              len(r.prompt) + len(r.generated) - 1
+                              for _, r in rows))
         return finished, b, decode_s
 
     def _sample(self, r: Request, logits_row: np.ndarray) -> int:
@@ -984,31 +1030,36 @@ class Engine:
         side state (request map, chunked-prefill map, last tokens,
         modality rows)."""
         tr = self.tracer
-        for src, dst in self.kv.compact().items():
-            self._defrag_moves += 1
-            if src in self.active:
-                r = self.active.pop(src)
-                r.slot = dst
-                self.active[dst] = r
-            else:
-                self._prefilling[dst] = self._prefilling.pop(src)
-                if src in self._inflight:  # async chain follows its slot
-                    self._inflight[dst] = self._inflight.pop(src)
+        with (tr.span("kv.compact") if tr is not None else _NO_SPAN) as sp:
+            moves = self.kv.compact()
+            for src, dst in moves.items():
+                self._defrag_moves += 1
+                if src in self.active:
+                    r = self.active.pop(src)
+                    r.slot = dst
+                    self.active[dst] = r
+                else:
+                    self._prefilling[dst] = self._prefilling.pop(src)
+                    if src in self._inflight:  # async chain follows its slot
+                        self._inflight[dst] = self._inflight.pop(src)
+                if tr is not None:
+                    moved = (self.active.get(dst)
+                             or self._prefilling.get(dst, [None])[0])
+                    tr.event("defrag_move", src=src, dst=dst,
+                             rid=moved.rid if moved is not None else None)
+                    if moved is not None:
+                        tr.request_annotate(moved.rid, slot=dst)
+                self.last_tok = self.last_tok.at[dst].set(self.last_tok[src])
+                # SWAP modality rows (not copy): the in-flight request keeps
+                # its features at dst, and the freed src slot inherits dst's
+                # old row — the per-slot feature set stays a permutation, so
+                # future occupants never see a duplicated/lost row.
+                for k, v in self._extra.items():
+                    src_row = v[src]
+                    self._extra[k] = v.at[src].set(v[dst]).at[dst].set(
+                        src_row)
             if tr is not None:
-                moved = (self.active.get(dst)
-                         or self._prefilling.get(dst, [None])[0])
-                tr.event("defrag_move", src=src, dst=dst,
-                         rid=moved.rid if moved is not None else None)
-                if moved is not None:
-                    tr.request_annotate(moved.rid, slot=dst)
-            self.last_tok = self.last_tok.at[dst].set(self.last_tok[src])
-            # SWAP modality rows (not copy): the in-flight request keeps
-            # its features at dst, and the freed src slot inherits dst's
-            # old row — the per-slot feature set stays a permutation, so
-            # future occupants never see a duplicated/lost row.
-            for k, v in self._extra.items():
-                src_row = v[src]
-                self._extra[k] = v.at[src].set(v[dst]).at[dst].set(src_row)
+                sp["moves"] = len(moves)
 
 
 def _splice_cache(cache, single, slot: int):

@@ -10,17 +10,21 @@ metrics layer makes the engine emit it.  Three histograms:
   latency distribution);
 * ``step_ms`` — every engine iteration, including admission-only ones.
 
-plus throughput counters and a per-step snapshot of the GEMV dispatcher's
-decision counters (``repro.kernels.dispatch.dispatch_stats``: plan-cache
-program hits, per-backend kernel picks, gemv-vs-matmul path mix).  The
-snapshots are *deltas against the engine's start*, so one process can run
-several engines/policies and attribute decisions to each (serve_bench
-relies on this to show the scheduler's batch shaping moving the mix).
+plus throughput counters, a small record per engine step (time, step
+latency, decode batch, occupancy, queue depth) and the GEMV dispatcher's
+decision counters over the whole run
+(``repro.kernels.dispatch.dispatch_stats``: plan-cache program hits,
+per-backend kernel picks, gemv-vs-matmul path mix).  Those counters are a
+*delta against the engine's start*, so one process can run several
+engines/policies and attribute decisions to each (serve_bench relies on
+this to show the scheduler's batch shaping moving the mix).  They move
+only when a plan is made, at trace time, so they are read for the
+document, not copied into every step record.
 
 Everything exports as one schema-versioned JSON document
 (:meth:`ServingMetrics.to_dict` / :meth:`to_json`).  Laptop-scale design:
 histograms keep raw samples and report exact percentiles up to a bounded
-reservoir cap (see :class:`Histogram`); per-step snapshots are bounded by
+reservoir cap (see :class:`Histogram`); per-step records are bounded by
 ``MAX_STEP_RECORDS`` with aggregates keeping full fidelity.
 """
 
@@ -41,7 +45,7 @@ import numpy as np
 # section when any lookup ran.
 SCHEMA_VERSION = 3
 
-# Per-step snapshots kept in memory; older entries are dropped (the
+# Per-step records kept in memory; older entries are dropped (the
 # aggregate histograms/counters keep full fidelity).
 MAX_STEP_RECORDS = 4096
 
@@ -213,7 +217,7 @@ class ServingMetrics:
         self.counters["prefill_chunks"] += 1
         self.counters["prefill_tokens"] += n_tokens
 
-    # -- per-step snapshot ---------------------------------------------------
+    # -- per-step record -----------------------------------------------------
 
     def dispatch_delta(self) -> dict:
         return _diff_counters(_dispatch_snapshot(), self._dispatch_base)
@@ -233,7 +237,6 @@ class ServingMetrics:
             "decode_batch": decode_batch,
             "active": n_active,
             "queue": queue_depth,
-            "dispatch": self.dispatch_delta(),
         })
         if len(self.steps) > MAX_STEP_RECORDS:
             del self.steps[:len(self.steps) - MAX_STEP_RECORDS]

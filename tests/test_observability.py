@@ -163,6 +163,74 @@ def test_span_contextmanager_records_body_attrs():
     assert s.dur_us == pytest.approx(1e3)
 
 
+class _RecordingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each
+    annotation's name, its metadata, and how often it was entered."""
+
+    made: list = []
+
+    def __init__(self, name, **meta):
+        self.name, self.meta, self.entered = name, dict(meta), 0
+        self.late: dict = {}
+        self.made.append(self)
+
+    def __enter__(self):
+        self.entered += 1
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **meta):
+        self.late.update(meta)
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    monkeypatch.setattr(_RecordingAnnotation, "made", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _RecordingAnnotation)
+    return _RecordingAnnotation.made
+
+
+def test_span_enters_one_profiler_annotation(annotations):
+    tr = Tracer()
+    with tr.span("kv.merge", bucket=8, rid=3, shape=(1, 2)) as attrs:
+        assert len(annotations) == 1 and annotations[0].entered == 1
+        attrs["moves"] = 2
+    (ann,) = annotations
+    assert ann.name == "engine.kv.merge"
+    # scalar attrs and the rid at entry; what the body added at exit
+    assert ann.meta == {"bucket": 8, "rid": 3}
+    assert ann.late == {"moves": 2}
+    (s,) = tr.spans
+    assert s.attrs == {"bucket": 8, "shape": (1, 2), "moves": 2}
+    with tr.span("queued", cat="phase"):
+        pass
+    assert annotations[1].name == "phase.queued"
+
+
+def test_span_parents_follow_the_thread_that_opened_them():
+    tr = Tracer()
+    seen = []
+
+    def other():
+        with tr.span("elsewhere"):
+            pass
+        seen.append(True)
+
+    with tr.span("step"):
+        with tr.span("decode_step"):
+            tr.add_span("prefill_chunk", 0.0, 1.0)
+            t = threading.Thread(target=other)
+            t.start()
+            t.join()
+    tr.add_span("late", 0.0, 1.0)
+    parents = {s.name: s.parent for s in tr.spans}
+    assert seen == [True]
+    assert parents == {"prefill_chunk": "decode_step", "elsewhere": None,
+                       "decode_step": "step", "step": None, "late": None}
+
+
 def test_install_uninstall_semantics():
     a, b = Tracer(), Tracer()
     assert current_tracer() is None
@@ -542,6 +610,117 @@ def test_engine_traced_run_complete_span_trees(cfg, params, tmp_path):
     spath = export.summary_path(str(tpath))
     export.write_summary(tr, spath)
     assert json.loads(open(spath).read())["schema"] == 1
+
+
+DECODE_CHILDREN = ["kv.slice", "decode.launch", "kv.merge", "decode.fetch",
+                   "decode.sample"]
+PREFILL_CHILDREN = ["prefill.launch", "kv.splice", "prefill.fetch",
+                    "prefill.sample"]
+
+
+def _traced_engine_run(cfg, params, tracer):
+    """Three requests through a 2-slot engine, one step at a time; returns
+    ``[(decode_step span or None, sum of kv_valid_len over the rows that
+    decoded and stayed)]`` per step."""
+    eng = Engine(cfg, params, batch_slots=2, max_len=MAX_LEN,
+                 scheduler="fcfs", tracer=tracer)
+    per_step = []
+    try:
+        for i, p in enumerate(_prompts(cfg, [5, 7, 4], seed=21)):
+            eng.submit(Request(rid=i, prompt=p, max_new_tokens=4))
+        for _ in range(50):
+            n = len(tracer.spans) if tracer is not None else 0
+            eng.step()
+            if tracer is not None:
+                new = list(tracer.spans)[n:]
+                ds = [s for s in new if s.name == "decode_step"]
+                valid = eng.kv.kv_valid_len()
+                per_step.append((ds[0] if ds else None,
+                                 int(sum(valid[s] for s in eng.active))))
+            if not eng.active and not eng.queue:
+                break
+    finally:
+        uninstall_tracer(tracer)
+    return per_step
+
+
+def _children(spans, parent):
+    end = parent.start_us + parent.dur_us
+    return sorted((s for s in spans if s.parent == parent.name
+                   and parent.start_us <= s.start_us
+                   and s.start_us + s.dur_us <= end),
+                  key=lambda s: s.start_us)
+
+
+def test_engine_decode_step_has_its_five_children(cfg, params):
+    tr = Tracer()
+    _traced_engine_run(cfg, params, tr)
+    spans = list(tr.spans)
+    steps = [s for s in spans if s.name == "decode_step"]
+    assert len(steps) >= 3
+    for ds in steps:
+        kids = _children(spans, ds)
+        assert [k.name for k in kids] == DECODE_CHILDREN
+        assert all(k.cat == "engine" for k in kids)
+        # in order, without overlap, inside the parent's interval
+        for a, b in zip(kids, kids[1:]):
+            assert a.start_us + a.dur_us <= b.start_us
+        assert kids[0].attrs["bucket"] == ds.attrs["bucket"]
+        assert kids[-1].attrs["rows"] == ds.attrs["rows"]
+        assert kids[-1].attrs["finished"] == ds.attrs["finished"]
+    for w in (s for s in spans if s.name == "prefill_wave"):
+        assert [k.name for k in _children(spans, w)] == PREFILL_CHILDREN
+
+
+def test_engine_step_span_tree(cfg, params):
+    tr = Tracer()
+    _traced_engine_run(cfg, params, tr)
+    spans = list(tr.spans)
+    top = [s for s in spans if s.name == "step"]
+    assert top and all(s.parent is None for s in top)
+    assert sum(s.attrs["admitted"] for s in top) == 3
+    assert any(s.attrs["decode_rows"] > 0 for s in top)
+    for s in spans:
+        if s.name in ("step.admit", "prefill_wave", "kv.compact",
+                      "decode_step"):
+            assert s.parent == "step", s
+    # two compaction passes per step, each with its move count
+    compacts = [s for s in spans if s.name == "kv.compact"]
+    assert len(compacts) == 2 * len(top)
+    assert all("moves" in s.attrs for s in compacts)
+    # request phases cross steps: no parent
+    assert all(s.parent is None for s in spans if s.cat == "phase")
+
+
+def test_engine_span_names_and_attrs_kept(cfg, params):
+    tr = Tracer()
+    per_step = _traced_engine_run(cfg, params, tr)
+    spans = list(tr.spans)
+    waves = [s for s in spans if s.name == "prefill_wave"]
+    assert waves and all(s.cat == "engine" and s.track == "engine"
+                         for s in waves)
+    assert sum(s.attrs["requests"] for s in waves) == 3
+    assert sum(s.attrs["tokens"] for s in waves) == 5 + 7 + 4
+    steps = [s for s in spans if s.name == "decode_step"]
+    assert all({"bucket", "active", "defrag_moves", "finished", "rows",
+                "live_positions"} <= set(s.attrs) for s in steps)
+    # live_positions is the decoded rows' kv_valid_len after the step
+    checked = 0
+    for ds, valid in per_step:
+        if ds is not None and ds.attrs["finished"] == 0:
+            assert ds.attrs["live_positions"] == valid
+            checked += 1
+    assert checked >= 2
+
+
+def test_engine_without_tracer_enters_no_annotation(cfg, params,
+                                                    annotations):
+    assert _traced_engine_run(cfg, params, None) == []
+    assert annotations == []
+    _traced_engine_run(cfg, params, Tracer())
+    names = {a.name for a in annotations}
+    assert {"engine.step", "engine.decode_step", "engine.kv.merge"} <= names
+    assert all(a.entered == 1 for a in annotations)
 
 
 def test_engine_traced_preemption_records_phase(cfg, params):

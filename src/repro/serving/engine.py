@@ -318,7 +318,10 @@ class Engine:
         # final chunk (tracer clock)}.  Keys are a subset of _prefilling.
         self._inflight: dict[int, dict] = {}
         self.expired: list[Request] = []
-        self.last_tok = jnp.zeros((batch_slots, 1), jnp.int32)
+        # Each slot's last sampled token, the next decode step's input: a
+        # host buffer written in place (a device scatter per token costs
+        # eight eager programs); each step sends its bucket's rows once.
+        self.last_tok = np.zeros((batch_slots, 1), np.int32)
         self._extra = self._make_extra(batch_slots)
         self._rngs: dict[int, np.random.Generator] = {}
         self._admit_seq = 0
@@ -913,7 +916,7 @@ class Engine:
         r.generated.append(tok)
         r.slot = slot
         self.active[slot] = r
-        self.last_tok = self.last_tok.at[slot, 0].set(tok)
+        self.last_tok[slot, 0] = tok
         if self.tracer is not None:
             # prefill -> decode at the first sampled token
             self.tracer.request_phase(r.rid, "decode", slot=slot)
@@ -954,7 +957,9 @@ class Engine:
                          {s: (self.kv.slot_view(s), self._prefilling[s][1])
                           for s in self._prefilling if s < b})
                 cache_b = self.kv.slice_prefix(b)
-                last_b = self.last_tok[:b]
+                # a copy: the jit may alias a host argument without one
+                # (CPU backend), and the buffer changes after this step
+                last_b = np.array(self.last_tok[:b])
                 extra_b = {k: v[:b] for k, v in self._extra.items()}
             with (tr.span("decode.launch") if tr is not None
                   else _NO_SPAN), self._mesh_ctx():
@@ -976,13 +981,13 @@ class Engine:
                 for slot, r in rows:
                     tok = self._sample(r, logits_np[slot])
                     r.generated.append(tok)
-                    self.last_tok = self.last_tok.at[slot, 0].set(tok)
+                    self.last_tok[slot, 0] = tok
                     self.metrics.tokens_generated(1)
                     if self._should_finish(r, tok):
                         self._finish(r, slot, now)
                         finished.append(r)
                 if tr is not None:
-                    ss["finished"] = len(finished)
+                    ss.update(finished=len(finished), host_writes=len(rows))
             if tr is not None:
                 # live_positions: the positions the decoded rows' caches
                 # hold after this step (their kv_valid_len), counted on the
@@ -1049,7 +1054,7 @@ class Engine:
                              rid=moved.rid if moved is not None else None)
                     if moved is not None:
                         tr.request_annotate(moved.rid, slot=dst)
-                self.last_tok = self.last_tok.at[dst].set(self.last_tok[src])
+                self.last_tok[dst] = self.last_tok[src]
                 # SWAP modality rows (not copy): the in-flight request keeps
                 # its features at dst, and the freed src slot inherits dst's
                 # old row — the per-slot feature set stays a permutation, so

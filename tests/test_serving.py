@@ -736,6 +736,119 @@ def test_engine_mixed_lengths_rwkv():
 
 
 # --------------------------------------------------------------------------
+# Engine: the last-token feed of the decode step
+# --------------------------------------------------------------------------
+
+
+def _record_feed(eng):
+    """Wrap the engine's jitted decode step and its slot compaction.
+
+    Each decode call records the token input it was given (the object
+    itself and a copy), whether that input shares memory with the engine's
+    host buffer, and each active slot's ``(rid, last generated token)``.
+    Each compaction records ``(rid, dst, decode calls so far)`` for every
+    active request it moved."""
+    calls, moved = [], []
+    jit_decode, compact = eng._jit_decode, eng.kv.compact
+
+    def decode(params, last_tok, cache, extra):
+        calls.append((last_tok, np.array(last_tok),
+                      np.shares_memory(last_tok, eng.last_tok),
+                      {s: (r.rid, r.generated[-1])
+                       for s, r in eng.active.items()}))
+        return jit_decode(params, last_tok, cache, extra)
+
+    def compact_and_record():
+        moves = compact()
+        moved.extend((eng.active[src].rid, dst, len(calls))
+                     for src, dst in moves.items() if src in eng.active)
+        return moves
+
+    eng._jit_decode = decode
+    eng.kv.compact = compact_and_record
+    return calls, moved
+
+
+def test_engine_decode_feed_carries_last_tokens(cfg, params):
+    """Each decode step's token input holds every active row's last
+    generated token, also for a request compaction just moved; the input
+    is a copy of the engine's host buffer (later writes to the buffer do
+    not reach it); the greedy streams match b=1 serial decoding; and
+    ``decode.sample`` records one host write per sampled row."""
+    from repro.observability.trace import Tracer, uninstall_tracer
+
+    lengths, max_new = [5, 9, 3, 12, 7, 4], [2, 9, 3, 8, 4, 6]
+    prompts = _prompts(cfg, lengths, seed=31)
+    tr = Tracer()
+    eng = Engine(cfg, params, batch_slots=4, max_len=MAX_LEN, tracer=tr)
+    try:
+        calls, moved = _record_feed(eng)
+        for i, p in enumerate(prompts):
+            eng.submit(Request(rid=i, prompt=p, max_new_tokens=max_new[i]))
+        done = {r.rid: r for r in eng.run_until_drained()}
+    finally:
+        uninstall_tracer(tr)
+    assert sorted(done) == list(range(len(prompts)))
+    for i, p in enumerate(prompts):
+        assert done[i].generated == _serial_greedy(cfg, params, p,
+                                                   max_new[i]), i
+    assert calls
+    for fed, at_call, shared, rows in calls:
+        assert not shared, "the decode input is a view of the buffer"
+        # the buffer was written after the call; the input kept its values
+        np.testing.assert_array_equal(np.asarray(fed), at_call)
+        assert rows and all(at_call[s, 0] == tok
+                            for s, (_, tok) in rows.items()), rows
+    # a request moved by compaction was fed from its new row afterwards
+    assert moved, "the run made no compaction with moves"
+    assert any(calls[k][3].get(dst, (None,))[0] == rid
+               for rid, dst, n in moved for k in range(n, len(calls)))
+    samples = [s for s in tr.spans if s.name == "decode.sample"]
+    assert len(samples) == len(calls)
+    assert all(s.attrs["host_writes"] == s.attrs["rows"] == len(rows)
+               for s, (_, _, _, rows) in zip(samples, calls))
+
+
+def test_engine_decode_compiles_once_per_bucket(cfg, params):
+    """One kind of token input in every step: with ``jax.log_compiles``,
+    a second pass of the same traffic compiles and traces no decode step,
+    and each bucket's decode step compiled once."""
+    import logging
+    import re
+
+    class Lines(logging.Handler):
+        def __init__(self):
+            super().__init__(logging.DEBUG)
+            self.lines = []
+
+        def emit(self, record):
+            self.lines.append(record.getMessage())
+
+    lengths, max_new = [5, 9, 3, 12, 7], [2, 7, 3, 5, 4]
+    eng = Engine(cfg, params, batch_slots=4, max_len=MAX_LEN)
+    log, h = logging.getLogger("jax"), Lines()
+    log.addHandler(h)
+    passes = []
+    try:
+        with jax.log_compiles():
+            for rid0 in (0, 100):
+                n = len(h.lines)
+                for i, p in enumerate(_prompts(cfg, lengths, seed=32)):
+                    eng.submit(Request(rid=rid0 + i, prompt=p,
+                                       max_new_tokens=max_new[i]))
+                eng.run_until_drained()
+                passes.append(h.lines[n:])
+    finally:
+        log.removeHandler(h)
+    compiled = [re.search(r"int32\[(\d+),1\]", m).group(1)
+                for m in passes[0]
+                if m.startswith("Compiling jit(_decode_fn)")]
+    assert compiled and len(compiled) == len(set(compiled)), compiled
+    assert eng._jit_decode._cache_size() == len(compiled)
+    assert not [m for m in passes[1] if "_decode_fn" in m], passes[1]
+
+
+# --------------------------------------------------------------------------
 # Deprecation shims (PR-3 pattern: warn once per call site)
 # --------------------------------------------------------------------------
 

@@ -263,6 +263,73 @@ def test_sharded_defrag_keeps_prefix_and_shardings():
     assert "model" in r["k_spec"]  # KV really is sharded on heads
 
 
+def test_sharded_decode_feed_carries_last_tokens():
+    """Sharded mode takes the same host token feed: on a (1, 2) mesh each
+    decode input is a copy of the host buffer whose active rows hold each
+    request's last token (also after a compaction move), its replicated
+    in_sharding places it, the greedy streams equal the single-host
+    engine's, and ``decode.sample`` records one host write per row."""
+    r = run_sub(_SERVE_BOTH + textwrap.dedent("""
+    from repro.observability.trace import Tracer, uninstall_tracer
+
+    lengths, max_new = [5, 9, 3, 12, 7, 4], [2, 9, 3, 8, 4, 6]
+    cfg = ARCHS["olmo-1b"].reduced()
+    params = lm.init_lm(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, cfg.vocab, L).astype(np.int32)
+               for L in lengths]
+
+    def run(mesh_shape, tracer=None):
+        mesh = (make_mesh(mesh_shape, ("data", "model"))
+                if mesh_shape else None)
+        eng = Engine(cfg, params, batch_slots=4, max_len=64, mesh=mesh,
+                     tracer=tracer)
+        calls, moved = [], []
+        jit_decode, compact = eng._jit_decode, eng.kv.compact
+
+        def decode(params, last_tok, cache, extra):
+            calls.append((last_tok, np.array(last_tok),
+                          bool(np.shares_memory(last_tok, eng.last_tok)),
+                          {s: r.generated[-1]
+                           for s, r in eng.active.items()}))
+            return jit_decode(params, last_tok, cache, extra)
+
+        def compact_and_record():
+            moves = compact()
+            moved.extend(src for src in moves if src in eng.active)
+            return moves
+
+        eng._jit_decode, eng.kv.compact = decode, compact_and_record
+        for i, p in enumerate(prompts):
+            eng.submit(Request(rid=i, prompt=p, max_new_tokens=max_new[i]))
+        done = {r.rid: r.generated for r in eng.run_until_drained()}
+        rows_ok = all(at[s, 0] == tok for _, at, _, rows in calls
+                      for s, tok in rows.items())
+        kept = all(np.array_equal(np.asarray(fed), at)
+                   for fed, at, _, _ in calls)
+        return done, {"calls": len(calls), "moved": len(moved),
+                      "rows_ok": rows_ok, "kept": kept,
+                      "shared": any(c[2] for c in calls),
+                      "rows": [len(c[3]) for c in calls]}
+
+    single, _ = run(None)
+    tr = Tracer()
+    try:
+        sharded, feed = run((1, 2), tr)
+    finally:
+        uninstall_tracer(tr)
+    samples = [s.attrs for s in tr.spans if s.name == "decode.sample"]
+    print(json.dumps(dict(
+        feed, identical=single == sharded, n=len(sharded),
+        host_writes=[a["host_writes"] for a in samples],
+        sample_rows=[a["rows"] for a in samples])))
+    """))
+    assert r["identical"], "sharded decode diverged from single-host"
+    assert r["n"] == 6 and r["calls"] > 0 and r["moved"] > 0
+    assert r["rows_ok"] and r["kept"] and not r["shared"]
+    assert r["host_writes"] == r["sample_rows"] == r["rows"]
+
+
 @pytest.mark.slow
 def test_sharded_engine_all_families_token_identity():
     """Tentpole acceptance: every registered model family decodes

@@ -1,9 +1,10 @@
-"""Device milliseconds per decode step spent in programs other than the
-engine's jitted prefill and decode steps: the eager slices, merges,
-splices, compactions and per-slot token writes around them (the KV cache
-layer's copies).  From the profiler trace: the summed device time of the
-program executions that are not step programs (``reduce.STEP_PROGRAMS``),
-over the number of ``decode_step`` spans in the window."""
+"""One chip's device milliseconds per decode step spent in programs other
+than the engine's jitted prefill and decode steps: the eager slices,
+merges, splices, compactions and per-slot token writes around them (the
+KV cache layer's copies).  From the profiler trace: the summed device
+time of the first chip's program executions that are not step programs
+(``reduce.STEP_PROGRAMS``), over the number of ``decode_step`` spans in
+the window."""
 
 import reduce
 

@@ -1,8 +1,9 @@
-"""The decode step's share of the chip's peak memory bandwidth: the bytes
-the window's decode steps need (``costs.decode_step``: the weights once
-per step, each row's live KV read and its new KV written), over the
-summed ``decode_step`` span time of the engine's ``Tracer`` times the
-peak bandwidth, in percent."""
+"""The decode step's share of the peak memory bandwidth of the cell's
+chips: the bytes the window's decode steps need (``costs.decode_step``:
+the whole model's weights once per step, each row's live KV read and its
+new KV written), over the summed ``decode_step`` span time of the
+engine's ``Tracer`` times the cell's chips times one chip's peak
+bandwidth, in percent."""
 
 import costs
 
@@ -14,4 +15,4 @@ def read(ctx):
     secs = sum(s.end - s.start for s in ctx.engine_spans("decode_step")) / 1e9
     if not secs or not nbytes:
         return None
-    return 100.0 * nbytes / (secs * ctx.peaks["hbm_bytes_per_s"])
+    return 100.0 * nbytes / (secs * ctx.chips * ctx.peaks["hbm_bytes_per_s"])

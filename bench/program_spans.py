@@ -228,8 +228,17 @@ def idle_ms_inside(spans: list, ops: list) -> float | None:
     less the union of the device operations within it."""
     if not spans:
         return None
-    pairs = [(o.start, o.end) for o in ops]
-    idle = sum(s.end - s.start - reduce.union_ns(pairs, s.start, s.end)
+    pairs = sorted((o.start, o.end) for o in ops)
+    starts = [a for a, _ in pairs]
+    longest = max((b - a for a, b in pairs), default=0.0)
+
+    def near(s):
+        """The operations that can overlap span ``s``: none that starts
+        more than the longest operation before it."""
+        return pairs[bisect.bisect_left(starts, s.start - longest):
+                     bisect.bisect_left(starts, s.end)]
+
+    idle = sum(s.end - s.start - reduce.union_ns(near(s), s.start, s.end)
                for s in spans)
     return idle / len(spans) / 1e6
 
@@ -288,12 +297,13 @@ def kv_launched_device_ms(prog: Program, window) -> float | None:
 
 
 def decode_program_hbm_pct(model: dict, peaks: dict, steps: list,
-                           programs: list) -> float | None:
-    """The decode program's share of peak memory bandwidth: the bytes
-    the decode ``steps`` need (``costs.decode_step`` from each step's own
-    ``rows`` and ``live_positions`` attrs), over the device time of the
-    ``programs`` executions that ran inside those steps times the peak,
-    in percent."""
+                           programs: list, chips: int) -> float | None:
+    """The decode program's share of the peak memory bandwidth of
+    ``chips`` chips: the bytes the decode ``steps`` need
+    (``costs.decode_step`` from each step's own ``rows`` and
+    ``live_positions`` attrs), over the device time of the ``programs``
+    executions (one chip's) that ran inside those steps times ``chips``
+    times one chip's peak, in percent."""
     steps = sorted((s for s in steps if "live_positions" in s.attrs),
                    key=lambda s: s.start)
     nbytes = 0.0
@@ -307,7 +317,7 @@ def decode_program_hbm_pct(model: dict, peaks: dict, steps: list,
              if inside((p.start + p.end) / 2))
     if not ns or not nbytes:
         return None
-    return 100.0 * nbytes / (ns / 1e9 * peaks["hbm_bytes_per_s"])
+    return 100.0 * nbytes / (ns / 1e9 * chips * peaks["hbm_bytes_per_s"])
 
 
 def decode_program_hbm_pct_launched(prog: Program, ctx) -> float | None:
@@ -319,7 +329,7 @@ def decode_program_hbm_pct_launched(prog: Program, ctx) -> float | None:
                 if m.attrs.get("span") == PREFIX + "decode.launch"]
     return decode_program_hbm_pct(
         ctx.model, ctx.peaks, prog.named("engine.decode_step", ctx.window),
-        programs)
+        programs, ctx.chips)
 
 
 def alignment_residual_us(prog: Program, ctx) -> list:

@@ -12,7 +12,12 @@ Every matrix product runs in float32 at ``Precision.HIGHEST``, one layer
 at a time (``lax.scan`` over the stacked layer weights, each cast to
 float32 inside the step), and the output head in vocabulary blocks, so
 the largest float32 temporary is one layer's weights or one block of the
-head.
+head.  On a mesh the weights arrive split over it (``weights.shardings``)
+and the partitioner splits these products with them: compiled for a
+four-chip v5e at Minitron-8B's 32 layers and 48 heads, the program
+gathers no weight (its one all-gather is of the ``[T, d]`` activations,
+and it reshards the head's blocks among the chips) and holds 1.6 GB of
+temporaries a chip beside its 4.1 GB share of the weights.
 
 ``precision="int8"`` is the control: every projection, the head
 included, runs as an int8 x int8 product (weights per output channel,

@@ -11,7 +11,10 @@ under a traffic mix (``bench/traffic/<mix>.json``) at the rate in
    many chips as the cell asks for, else exits 3 with no result;
 2. set-up: makes the weights from ``--seed`` on the device, builds the
    engine, warms up every program shape the mix's lengths can reach, then
-   drives the mix's ramp (``ramp_s``) until the slots are in steady use;
+   drives the mix's ramp (``ramp_s``) until the slots are in steady use.
+   A configuration that states a ``mesh`` runs on a (data, model) mesh of
+   the cell's chips: the weights are drawn split over it and the engine
+   is given it;
 3. drives the mix open loop on the wall clock for ``--seconds``, and with
    ``--trace 1`` records the profiler trace and the engine's ``Tracer``
    spans over that window;
@@ -100,12 +103,13 @@ def program_config(cell: spec.Cell):
     return cfg
 
 
-def engine_for(cell: spec.Cell, cfg, params, tracer=None):
+def engine_for(cell: spec.Cell, cfg, params, tracer=None, mesh=None):
     from repro.serving.engine import Engine
 
     mix = cell.traffic
     return Engine(cfg, params, batch_slots=cell.slots, max_len=mix["max_len"],
-                  scheduler=mix["engine"]["scheduler"], tracer=tracer)
+                  scheduler=mix["engine"]["scheduler"], tracer=tracer,
+                  mesh=mesh)
 
 
 def warm_up(eng, cell: spec.Cell, vocab: int) -> int:
@@ -174,22 +178,48 @@ def peak_memory(chips: int) -> int:
                for d in jax.devices()[:chips])
 
 
+def cell_mesh(cell: spec.Cell):
+    """The (data, model) mesh the cell's configuration states, or
+    ``None`` for a one-chip configuration."""
+    if cell.mesh_shape is None:
+        return None
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh(cell.mesh_shape, ("data", "model"))
+
+
 def set_up(cell: spec.Cell, seed: int, *, tracer=None, t_start: float,
            log=lambda s: None):
-    """Weights from ``seed`` on the device, the engine, and its warm-up.
-    Returns ``(weights, engine)``."""
+    """Weights from ``seed`` on the device (split over the cell's mesh, if
+    it has one), the engine, and its warm-up.  Returns
+    ``(weights, engine)``; on a mesh the weights are ``None``, since the
+    engine placed its own copy and a second would double each chip's
+    weights (``check_weights`` draws them again for the check)."""
     import jax
 
     import weights
 
     cfg = program_config(cell)
-    w = jax.block_until_ready(weights.make_weights(cell.model, seed))
+    mesh = cell_mesh(cell)
+    w = jax.block_until_ready(weights.make_weights(cell.model, seed, mesh))
     log(f"bench: weights made at {time.perf_counter() - t_start:.2f} s")
-    eng = engine_for(cell, cfg, w, tracer)
+    eng = engine_for(cell, cfg, w, tracer, mesh)
+    if mesh is not None:
+        w = None
     n_warm = warm_up(eng, cell, cell.model["vocab"])
     log(f"bench: {n_warm} warm-up requests done at "
         f"{time.perf_counter() - t_start:.2f} s")
     return w, eng
+
+
+def check_weights(cell: spec.Cell, seed: int, w):
+    """The weights the engine was given: ``w``, or on a mesh the same
+    draw from ``seed`` again."""
+    if w is not None:
+        return w
+    import weights
+
+    return weights.make_weights(cell.model, seed, cell_mesh(cell))
 
 
 def serve_mix(eng, cell: spec.Cell, rate: float, seed: int, seconds: float,
@@ -278,7 +308,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
             with jax.profiler.TraceAnnotation("bench.window_end",
                                               tracer_us=tracer.now_us()):
                 pass
+            t0 = time.perf_counter()
             jax.profiler.stop_trace()
+            log(f"bench: trace written in {time.perf_counter() - t0:.2f} s")
 
     run = serve_mix(eng, cell, float(cell.cell_file["rate_per_s"]), seed,
                     seconds, on_open=on_open, on_close=on_close,
@@ -295,6 +327,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
 
         import reduce
 
+        t0 = time.perf_counter()
         ctx = reduce.Context.build(
             trace_dir=trace_dir, tracer=tracer, steps=run["steps"],
             model=cell.model, peaks=peaks, chips=cell.chips)
@@ -311,6 +344,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
                                            "unit": metric["unit"]}
         result["breakdown"] = ctx.breakdown()
         result["busy_s"], result["window_s"] = ctx.busy_s(), ctx.window_s()
+        log(f"bench: trace read in {time.perf_counter() - t0:.2f} s")
     else:
         values = dict(e2e, setup_s=setup_s)
         metrics = {x["name"]: {"value": values[x["name"]],
@@ -330,6 +364,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     gc.collect()
     ref = spec.reference_module(cell.config, cell.root)
     t0 = time.perf_counter()
+    w = check_weights(cell, seed, w)
     length = int(cell.traffic["max_len"])
     scored = (check.served_gaps(ref, w, cell.model, reqs, length)
               if reqs else None)

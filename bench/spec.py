@@ -65,12 +65,33 @@ class Cell:
     end_to_end: list      # BENCHMARK.json metrics this cell reports
     per_layer: list
     root: str = ROOT
+    # (data, model): the configuration's ``mesh``; None on one chip
+    mesh_shape: tuple[int, int] | None = None
 
     @property
     def slots(self) -> int:
         """KV slots: the mix's, unless the cell cuts them to fit."""
         return int(self.cell_file.get("batch_slots",
                                       self.traffic["batch_slots"]))
+
+
+def mesh_shape(config: dict, chips: int) -> tuple[int, int] | None:
+    """A configuration without ``mesh`` runs on one chip; one with
+    ``"mesh": {"data": D, "model": M}`` on exactly ``D * M`` chips."""
+    if "mesh" not in config:
+        if chips != 1:
+            raise SpecError(f"the cell asks for {chips} chips, but its "
+                            f"configuration states no mesh")
+        return None
+    try:
+        shape = (int(config["mesh"]["data"]), int(config["mesh"]["model"]))
+    except (KeyError, TypeError, ValueError) as e:
+        raise SpecError(f"mesh wants {{\"data\": D, \"model\": M}}, not "
+                        f"{config['mesh']!r}") from e
+    if min(shape) < 1 or shape[0] * shape[1] != chips:
+        raise SpecError(f"the configuration's mesh {shape} (data, model) "
+                        f"does not cover the cell's {chips} chips")
+    return shape
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -99,7 +120,7 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
         name=name, chips=int(w["chips"]), config_name=w["config"],
         config=config, model=model_sizes(config),
         traffic_name=w["traffic"], traffic=traffic, cell_file=cell_file,
-        root=root,
+        root=root, mesh_shape=mesh_shape(config, int(w["chips"])),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, name)],
     )

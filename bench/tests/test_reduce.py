@@ -142,3 +142,53 @@ def test_breakdown_names_programs_and_host_spans(ctx):
     assert b["device_ops"][0][0] == "jit_dynamic_update_slice/%copy.1"
     idle = sum(s for _, s in b["idle_gaps"])
     assert idle == pytest.approx(ctx.window_s() - ctx.busy_s())
+
+
+# What each reader gave on the recorded traces before the shares were read
+# against every chip of the cell; a one-chip Context must still give it.
+ONE_CHIP = {
+    "olmo-1b.decode-heavy": {
+        "device.idle_pct": 40.58843513353426,
+        "engine.decode_idle_ms": 17.26273538461656,
+        "kernel.gemv_roofline": 38.19701734084294,
+        "kv.offstep_device_ms": 6.599396461538461,
+        "model.decode_hbm_pct": 7.9152947063497034,
+        "model.decode_mfu_pct": 0.23028431261264357},
+    "olmo-1b.decode-heavy.spans": {
+        "device.idle_pct": 42.21192682495225,
+        "engine.decode_idle_ms": 18.2078652500089,
+        "kernel.gemv_roofline": 38.21305441466566,
+        "kv.offstep_device_ms": 6.59843875,
+        "model.decode_hbm_pct": 7.377615969039433,
+        "model.decode_mfu_pct": 0.22286891106664597,
+        "model.decode_program_hbm_pct": 17.12295823194941},
+}
+WHOLE_MODEL_SHARES = ("model.decode_hbm_pct", "model.decode_mfu_pct",
+                      "model.decode_program_hbm_pct")
+
+
+def _recorded(name, chips):
+    m = spec.load_cell("olmo-1b.decode-heavy").model
+    return reduce.load_records(os.path.join(os.path.dirname(RECORDED), name),
+                               model=m, peaks=PEAKS, chips=chips)
+
+
+@pytest.mark.parametrize("name", sorted(ONE_CHIP))
+def test_one_chip_readings_are_unchanged(name):
+    ctx = _recorded(name, 1)
+    for metric, want in ONE_CHIP[name].items():
+        assert spec.layer_metric_reader(metric)(ctx) == want, metric
+
+
+def test_whole_model_shares_are_read_against_every_chip():
+    """The model's work over four chips' peak reads a quarter of it over
+    one chip's; a kernel's share stays one chip's shard against one
+    chip's peak."""
+    one, four = (_recorded("olmo-1b.decode-heavy.spans", n) for n in (1, 4))
+    for metric in WHOLE_MODEL_SHARES:
+        read = spec.layer_metric_reader(metric)
+        assert read(four) == pytest.approx(read(one) / 4, rel=1e-12), metric
+    for metric in ("kernel.gemv_roofline", "kv.offstep_device_ms",
+                   "engine.decode_idle_ms"):
+        read = spec.layer_metric_reader(metric)
+        assert read(four) == read(one), metric

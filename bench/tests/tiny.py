@@ -21,7 +21,10 @@ OVERRIDES = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 4,
 
 def cell(traffic_name: str = "decode-heavy", *, rate: float = 20.0,
          limit: float | None = LIMIT, config: str = "olmo-1b",
-         root: str = spec.ROOT) -> spec.Cell:
+         root: str = spec.ROOT,
+         mesh: tuple[int, int] | None = None) -> spec.Cell:
+    """The small cell; with ``mesh`` (data, model), its configuration
+    states that mesh and the cell asks for as many chips."""
     try:
         base = spec.load_cell(f"{config}.{traffic_name}", root)
     except spec.SpecError:  # a mix no cell of the benchmark uses
@@ -37,13 +40,17 @@ def cell(traffic_name: str = "decode-heavy", *, rate: float = 20.0,
     if config.startswith("minitron"):
         cfg["num_key_value_heads"] = 2
         cfg["program"]["overrides"]["n_kv_heads"] = 2
+    if mesh is not None:
+        cfg["mesh"] = {"data": mesh[0], "model": mesh[1]}
     mix = copy.deepcopy(base.traffic)
     mix.update(prompt=dict(mix["prompt"], median=12, min=8, max=32),
                output=dict(mix["output"], median=12, min=6, max=24),
                max_len=64, batch_slots=4, ramp_s=0.5, check_requests=8)
     return spec.Cell(
-        name=base.name, chips=1, config_name=base.config_name, config=cfg,
+        name=base.name, chips=mesh[0] * mesh[1] if mesh else 1,
+        config_name=base.config_name, config=cfg,
         model=spec.model_sizes(cfg), traffic_name=base.traffic_name,
         traffic=mix,
         cell_file={"rate_per_s": rate, "limits": {"max_logit_gap": limit}},
-        end_to_end=base.end_to_end, per_layer=base.per_layer, root=root)
+        end_to_end=base.end_to_end, per_layer=base.per_layer, root=root,
+        mesh_shape=mesh)

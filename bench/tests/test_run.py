@@ -113,3 +113,32 @@ def test_traced_run_reports_per_layer_metrics():
                       "device.idle_pct"}
     assert r["correct"]
     assert {"device_ops", "idle_gaps"} == set(r["breakdown"])
+
+
+def _fields(opts) -> dict:
+    return {k: (dict(v) if k == "advanced_configuration" else v)
+            for k in dir(opts) if not k.startswith("_")
+            for v in [getattr(opts, k)]}
+
+
+def test_one_chip_cell_traces_as_before(monkeypatch):
+    """A one-chip cell's traced run records its whole window under the
+    profiler options every traced run has had: host annotations only, no
+    Python tracer, the rest at their defaults."""
+    import jax
+
+    parents = jax.profiler.ProfileOptions()
+    parents.python_tracer_level = 0
+    parents.host_tracer_level = 1
+    given = []
+    start = jax.profiler.start_trace
+
+    def spy(log_dir, *a, profiler_options=None, **kw):
+        given.append(_fields(profiler_options))
+        return start(log_dir, *a, profiler_options=profiler_options, **kw)
+
+    monkeypatch.setattr(jax.profiler, "start_trace", spy)
+    r = run_tiny(tiny.cell(), trace=True)
+    assert given == [_fields(parents)]
+    # the whole window of 1.5 s, less the time the profiler takes to start
+    assert 1.0 < r["window_s"] < 1.7
